@@ -369,4 +369,6 @@ def main(argv):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
     main(sys.argv[1:])

@@ -29,4 +29,6 @@ def main() -> None:
 
 
 if __name__ == '__main__':
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
     main()

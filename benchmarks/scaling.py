@@ -48,4 +48,6 @@ def run(base_scenario: str = "s4_memory", n_seeds: int = 3, n_starts: int = 4):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
     run()

@@ -64,4 +64,6 @@ def run(n_seeds: int = 5, n_starts: int = 6, radar: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
     run()

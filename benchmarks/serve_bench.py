@@ -220,4 +220,6 @@ def main(argv) -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
     sys.exit(main(sys.argv[1:]))

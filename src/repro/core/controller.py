@@ -20,7 +20,7 @@ from .pgd import AnytimeConfig
 from .metrics import AllocationMetrics, evaluate
 from .multistart import multistart_solve
 from .problem import AllocationProblem, PenaltyParams
-from .rounding import round_and_polish
+from .rounding import cover_in_float64, round_and_polish
 
 
 @dataclass
@@ -117,17 +117,32 @@ class InfrastructureOptimizationController:
         method before advancing history, so a tick's availability applies
         to all window rows: interruptions are observed, not forecast, and
         an observed outage is assumed to persist over the horizon."""
-        unavailable = None
-        if self.spot_idx is not None and self.spot_availability is not None:
-            avail = np.asarray(self.spot_availability)
-            t = min(len(self.history), len(avail) - 1)
-            spot = np.asarray(self.spot_idx, np.int64)
-            unavailable = spot[avail[t] <= 0.0]
         return problem_from_demand(self.catalog, demand, params=self.params,
                                    allowed_idx=self.allowed_idx,
                                    normalize=self.normalize,
                                    terms=self.terms,
-                                   unavailable_idx=unavailable)
+                                   unavailable_idx=self._unavailable_idx())
+
+    def _unavailable_idx(self) -> Optional[np.ndarray]:
+        """Spot types interrupted at the tick about to be recorded (None
+        without a spot overlay)."""
+        if self.spot_idx is None or self.spot_availability is None:
+            return None
+        avail = np.asarray(self.spot_availability)
+        t = min(len(self.history), len(avail) - 1)
+        spot = np.asarray(self.spot_idx, np.int64)
+        return spot[avail[t] <= 0.0]
+
+    def _addable(self) -> np.ndarray:
+        """(n,) bool: the types this tick's problem may add units of."""
+        ok = np.ones(self.catalog.n, bool)
+        if self.allowed_idx is not None:
+            ok[:] = False
+            ok[np.asarray(self.allowed_idx, np.int64)] = True
+        unavailable = self._unavailable_idx()
+        if unavailable is not None:
+            ok[unavailable] = False
+        return ok
 
     # back-compat alias (pre-docs name)
     _problem = make_problem
@@ -182,12 +197,21 @@ class InfrastructureOptimizationController:
                      deadline_hit: bool = False) -> ControllerStep:
         """Record an allocation computed for this tick (by :meth:`step`, or
         externally by the batched fleet engine): compute churn and metrics,
-        advance ``x_current``, append to history. ``solver_iters`` optionally
+        advance ``x_current``, append to history. Counts that f32 rounding
+        left within its own error short of demand are first topped up in
+        float64 (:func:`repro.core.rounding.cover_in_float64`), so every
+        engine commits the same covering allocation. ``solver_iters`` optionally
         records the inner PGD iterations the solve took (see
         ``ControllerStep.solver_iters``); ``deadline_hit`` whether an
         anytime budget truncated it."""
         demand = np.asarray(demand, np.float64)
         x = np.asarray(counts, np.float64)
+        metrics = evaluate(self.catalog, x, demand)
+        if not metrics.satisfied:
+            # f32 rounding can stop within its own error of the demand
+            K, _, c = self.catalog.matrices()
+            x = cover_in_float64(K, c, self._addable(), x, demand)
+            metrics = evaluate(self.catalog, x, demand)
         churn = float(np.abs(x - (self.x_current if self.x_current is not None
                                   else np.zeros_like(x))).sum())
         # rounding may overshoot the relaxed solve's churn bound; record the
@@ -195,7 +219,7 @@ class InfrastructureOptimizationController:
         violation = 0.0 if replanned else max(0.0, churn - float(self.delta_max))
         self.x_current = x
         step = ControllerStep(demand=demand, counts=x,
-                              metrics=evaluate(self.catalog, x, demand),
+                              metrics=metrics,
                               churn=churn, replanned=replanned,
                               churn_violation=violation,
                               solver_iters=int(solver_iters),

@@ -8,6 +8,10 @@ import numpy as np
 
 from .catalog import Catalog
 
+# an allocation covers a demand when no resource falls short by more than
+# this, in raw units (float64)
+COVER_TOL = 1e-6
+
 
 @dataclass
 class AllocationMetrics:
@@ -42,7 +46,7 @@ def evaluate(catalog: Catalog, counts: np.ndarray, demand: np.ndarray) -> Alloca
         instance_diversity=int(used.sum()),
         provider_fragmentation=int((E @ used.astype(np.float64) > 0.5).sum()),
         overprovision_pct=float(over),
-        satisfied=bool(np.all(provided >= demand - 1e-6)),
+        satisfied=bool(np.all(provided >= demand - COVER_TOL)),
     )
 
 

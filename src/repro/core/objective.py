@@ -67,10 +67,23 @@ def value_and_grad(prob: AllocationProblem, x: jnp.ndarray):
 # Constraint handling (paper eq. 2): d - mu <= Kx <= d + g
 # ---------------------------------------------------------------------------
 
+# Every contraction against K that evaluates the constraint (or its barrier
+# and penalty gradients) runs at full f32 precision. TPU's default rounds f32
+# operands of a batched matmul to bf16; K x then errs by ~0.4% and rounding
+# accepts allocations that leave demand uncovered in float64. On CPU the
+# setting changes no bit.
+CONSTRAINT_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def constraint_matvec(K: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    """``K @ v`` at :data:`CONSTRAINT_PRECISION` (pass ``K.T`` for the
+    gradient side)."""
+    return jnp.matmul(K, v, precision=CONSTRAINT_PRECISION)
+
 
 def constraint_residuals(prob: AllocationProblem, x: jnp.ndarray):
     """Positive residual == satisfied. Returns (lower (m,), upper (m,))."""
-    Kx = prob.K @ x
+    Kx = constraint_matvec(prob.K, x)
     return Kx - (prob.d - prob.mu), (prob.d + prob.g) - Kx
 
 
@@ -102,7 +115,8 @@ def barrier_grad(prob: AllocationProblem, x: jnp.ndarray, t: jnp.ndarray) -> jnp
     lo, hi = constraint_residuals(prob, x)
     lo = jnp.maximum(lo, 1e-9)
     hi = jnp.maximum(hi, 1e-9)
-    return -(1.0 / t) * (prob.K.T @ (1.0 / lo)) + (1.0 / t) * (prob.K.T @ (1.0 / hi))
+    return (-(1.0 / t) * constraint_matvec(prob.K.T, 1.0 / lo)
+            + (1.0 / t) * constraint_matvec(prob.K.T, 1.0 / hi))
 
 
 def penalty(prob: AllocationProblem, x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
@@ -113,8 +127,9 @@ def penalty(prob: AllocationProblem, x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndar
 def penalty_grad(prob: AllocationProblem, x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     """∇ of the quadratic penalty (the barrier's fallback, paper impl. notes)."""
     lo, hi = constraint_residuals(prob, x)
-    g_lo = prob.K.T @ jnp.maximum(-lo, 0.0)   # d(sum max(-lo,0)^2)/dx = 2 K^T max(-lo,0) * d(-lo)/dKx ...
-    g_hi = prob.K.T @ jnp.maximum(-hi, 0.0)
+    # d(sum max(-lo,0)^2)/dx = 2 K^T max(-lo,0) * d(-lo)/dKx ...
+    g_lo = constraint_matvec(prob.K.T, jnp.maximum(-lo, 0.0))
+    g_hi = constraint_matvec(prob.K.T, jnp.maximum(-hi, 0.0))
     return w * (-2.0 * g_lo + 2.0 * g_hi)
 
 

@@ -303,11 +303,17 @@ class AnytimeReport(NamedTuple):
     remained (the returned iterate was truncated); a solve that converges
     or exhausts ``max_iters`` inside the budget reports False. ``chunks``
     counts chunk launches (0 when the budget was spent before the first
-    chunk — the caller then holds the projected, feasible warm start)."""
+    chunk — the caller then holds the projected, feasible warm start).
+    ``budget_ms`` is the ``deadline_ms`` the drive was given and
+    ``max_step_ms`` the longest interval between two consecutive clock
+    reads — the fenced init or one fenced chunk — so ``elapsed_ms`` never
+    exceeds ``budget_ms + max_step_ms``: the budget plus one chunk."""
 
     deadline_hit: bool
     elapsed_ms: float
     chunks: int
+    budget_ms: float
+    max_step_ms: float
 
 
 class PGDChunkState(NamedTuple):
@@ -344,8 +350,10 @@ def pgd_chunk_init(
     feasible). Jit/vmap-safe; callers wrap it in their own jitted impl."""
     x0 = project_fn(x0)
     fx = value_fn(x0)
+    # bb strongly typed, as every chunk returns it: a weak-typed init would
+    # make the second chunk call compile the chunk program again
     return PGDChunkState(
-        x=x0, fx=fx, g=grad_fn(x0), bb=jnp.asarray(cfg.step0),
+        x=x0, fx=fx, g=grad_fn(x0), bb=jnp.asarray(cfg.step0, jnp.float32),
         it=jnp.asarray(0), flat=jnp.asarray(0), done=jnp.asarray(False),
         x_best=x0, f_best=fx)
 
@@ -397,26 +405,35 @@ def run_anytime(init_fn, chunk_fn, cfg: PGDConfig,
     expires — whichever first. A non-positive ``deadline_ms`` returns the
     init state untouched: the projected warm start, always feasible.
 
-    Returns ``(state, AnytimeReport)``."""
+    The final state is fenced before the last clock read, so
+    ``elapsed_ms`` covers every chunk's device time. Returns ``(state,
+    AnytimeReport)``."""
     if anytime.deadline_ms is None:
         raise ValueError("run_anytime requires AnytimeConfig.deadline_ms; "
                          "branch to the untruncated engine when it is None")
     clock = anytime.clock
     chunk = max(1, int(anytime.chunk_iters))
     deadline = float(anytime.deadline_ms)
-    t0 = clock()
+    t0 = t_prev = clock()
     state = init_fn()
     it_end = 0
     deadline_hit = False
     chunks = 0
+    max_step = 0.0
     max_iters = int(cfg.max_iters)
     while it_end < max_iters and not bool(np.all(np.asarray(state.done))):
-        if (clock() - t0) * 1e3 >= deadline:
+        now = clock()       # the done-sync above fenced the previous step
+        max_step, t_prev = max(max_step, now - t_prev), now
+        if (now - t0) * 1e3 >= deadline:
             deadline_hit = True
             break
         it_end = min(it_end + chunk, max_iters)
         state = chunk_fn(state, jnp.asarray(it_end))
         chunks += 1
-    elapsed_ms = (clock() - t0) * 1e3
+    if not deadline_hit:
+        jax.block_until_ready(state)
+        now = clock()
+        max_step = max(max_step, now - t_prev)
     return state, AnytimeReport(deadline_hit=deadline_hit,
-                                elapsed_ms=elapsed_ms, chunks=chunks)
+                                elapsed_ms=(now - t0) * 1e3, chunks=chunks,
+                                budget_ms=deadline, max_step_ms=max_step * 1e3)

@@ -15,7 +15,10 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from . import objective as obj
+from .metrics import COVER_TOL
 from .problem import AllocationProblem
 
 
@@ -28,7 +31,7 @@ def greedy_round(prob: AllocationProblem, x_star: jnp.ndarray,
     target = prob.d - prob.mu
 
     def deficit(x):
-        return target - prob.K @ x
+        return target - obj.constraint_matvec(prob.K, x)
 
     def cond(state):
         x, it = state
@@ -57,8 +60,6 @@ def round_and_polish(prob: AllocationProblem, x_star: jnp.ndarray,
       * scale-down pass: drop units whose removal stays feasible,
         most-expensive first (mirrors CA's scale-down).
     Picks the feasible candidate with the lower objective."""
-    import repro.core.objective as obj
-
     a = scale_down(prob, greedy_round(prob, x_star, max_adds=max_adds))
     ceil_start = jnp.ceil(jnp.clip(x_star, prob.lb, prob.ub)) * prob.mask
     # tiny fractions should not force a whole node: drop < 0.05 before ceil
@@ -81,7 +82,7 @@ def scale_down(prob: AllocationProblem, x: jnp.ndarray,
 
     def removable(x):
         """cost of each type whose decrement keeps K x >= target."""
-        Kx = prob.K @ x
+        Kx = obj.constraint_matvec(prob.K, x)
         slack_ok = jnp.all(Kx[:, None] - prob.K >= target[:, None] - 1e-6, axis=0)
         can = slack_ok & (x >= 1.0) & (x - 1.0 >= prob.lb)
         return jnp.where(can, prob.c, -jnp.inf)
@@ -96,4 +97,39 @@ def scale_down(prob: AllocationProblem, x: jnp.ndarray,
         return x.at[i].add(-1.0), it + 1
 
     x, _ = jax.lax.while_loop(cond, body, (x, jnp.asarray(0)))
+    return x
+
+
+# a shortfall below this share of a resource's demand is f32 error of K x
+F32_GAP = 1e-5
+
+
+def cover_in_float64(K: np.ndarray, c: np.ndarray, addable: np.ndarray,
+                     counts: np.ndarray, demand: np.ndarray) -> np.ndarray:
+    """Finish greedy rounding in float64 raw units, on the host.
+
+    :func:`greedy_round` and :func:`scale_down` run in f32 on K scaled by
+    1/d and accept a deficit of 1e-6 of demand, about the f32 error of
+    K x; :func:`repro.core.metrics.evaluate` checks coverage in float64 raw
+    units and accepts :data:`~repro.core.metrics.COVER_TOL` absolute. An
+    allocation short by less than :data:`F32_GAP` of a resource's demand,
+    which is f32 error and not a real shortfall, gets the greedy pick (most
+    deficit covered per dollar among the ``addable`` types) one unit at a
+    time until that check holds. A covering allocation, or one short by
+    more, is returned unchanged."""
+    K = np.asarray(K, np.float64)
+    demand = np.asarray(demand, np.float64)
+    x = np.array(counts, np.float64)
+    deficit = demand - K @ x
+    if (np.all(deficit <= COVER_TOL)
+            or np.any(deficit > F32_GAP * np.abs(demand) + COVER_TOL)):
+        return x
+    while np.any(deficit > COVER_TOL):
+        score = np.where(addable, K.T @ np.maximum(deficit, 0.0)
+                         / np.maximum(c, 1e-9), -np.inf)
+        j = int(np.argmax(score))
+        if not score[j] > 0.0:
+            break
+        x[j] += 1.0
+        deficit = demand - K @ x
     return x

@@ -67,10 +67,11 @@ def phase1_point(prob: AllocationProblem, x0: jnp.ndarray, steps: int = 200,
     margin = margin_frac * band      # zero-width band -> zero margin
 
     def body(i, x):
-        Kx = prob.K @ x
+        Kx = obj.constraint_matvec(prob.K, x)
         lo_v = jnp.maximum((prob.d - prob.mu + margin) - Kx, 0.0)
         hi_v = jnp.maximum(Kx - (prob.d + prob.g - margin), 0.0)
-        grad = -2.0 * (prob.K.T @ lo_v) + 2.0 * (prob.K.T @ hi_v)
+        grad = (-2.0 * obj.constraint_matvec(prob.K.T, lo_v)
+                + 2.0 * obj.constraint_matvec(prob.K.T, hi_v))
         # Lipschitz-ish step from row norms; cheap and robust.
         L = 2.0 * jnp.sum(prob.K * prob.K) + 1e-6
         return obj.project(prob, x - (1.0 / L) * grad)

@@ -63,10 +63,8 @@ def pipeline_apply(fn_stage: Callable, params_stacked, x_micro, *,
         buf, _ = jax.lax.fori_loop(0, n_ticks, tick, (buf, carry))
         return buf
 
-    from .sharding import shard_map_compat
-    per = shard_map_compat(per_stage, mesh=mesh,
-                           in_specs=(P(axis), P()),
-                           out_specs=P(axis))
+    per = jax.shard_map(per_stage, mesh=mesh, in_specs=(P(axis), P()),
+                        out_specs=P(axis), check_vma=False)
     # every stage gets the full microbatch stream; outputs valid on last stage
     out = per(params_stacked, x_micro)
     # out is stacked over stages along the leading dim; take the last stage

@@ -27,18 +27,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 Rules = Dict[str, Optional[Tuple[str, ...]]]
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, across jax versions:
-    the top-level alias + ``check_vma`` appeared after 0.4.x; the installed
-    0.4.37 only has ``jax.experimental.shard_map`` with ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def _mesh_axes(mesh: Mesh) -> Tuple[str, ...]:
     return tuple(mesh.axis_names)
 
@@ -163,22 +151,11 @@ def constrain(x, *axes):
     rules = current_rules()
     if rules is None:
         return x
-    mesh = _get_abstract_mesh()
-    if mesh is None or not getattr(mesh, "axis_names", None):
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     spec = spec_for(axes, rules, mesh, shape=tuple(x.shape))
     return jax.lax.with_sharding_constraint(x, spec)
-
-
-def _get_abstract_mesh():
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    # jax 0.4.x: the ambient mesh set by `with mesh:` lives in the
-    # thread-local resource env (no AbstractMesh yet)
-    from jax.interpreters import pxla
-    mesh = pxla.thread_resources.env.physical_mesh
-    return None if mesh.empty else mesh
 
 
 def _axis_size(mesh, name) -> int:

@@ -29,11 +29,13 @@ from repro.core.incremental import (incremental_anytime_chunk,
                                     incremental_anytime_init,
                                     solve_incremental_info)
 from repro.core.multistart import make_starts
-from repro.core.pgd import AnytimeConfig, PGDConfig, PGDTrace, run_anytime
-from repro.core.objective import is_feasible, objective
+from repro.core.pgd import (AnytimeConfig, AnytimeReport, PGDConfig, PGDTrace,
+                            run_anytime)
+from repro.core.objective import CONSTRAINT_PRECISION, is_feasible, objective
 from repro.core.problem import AllocationProblem
 from repro.core.rounding import round_and_polish
 from repro.core.solver import SolverConfig, phase1_point, solve_relaxation
+from repro.kernels import resolve_interpret
 from repro.kernels.alloc_objective.ops import fleet_value_and_grad
 from repro.kernels.alloc_objective.ref import alloc_objective_fleet_value
 
@@ -80,7 +82,8 @@ def _project(prob: AllocationProblem, X: jnp.ndarray) -> jnp.ndarray:
 
 
 def _residuals(prob: AllocationProblem, X: jnp.ndarray):
-    KX = jnp.einsum("bmn,b...n->b...m", prob.K, X)
+    KX = jnp.einsum("bmn,b...n->b...m", prob.K, X,
+                    precision=CONSTRAINT_PRECISION)
     lo = KX - _bcast(prob.d - prob.mu, X)
     hi = _bcast(prob.d + prob.g, X) - KX
     return lo, hi
@@ -137,13 +140,12 @@ def _constraint_grads(prob: AllocationProblem, X: jnp.ndarray,
     lo, hi = _residuals(prob, X)
     lo_c = jnp.maximum(lo, 1e-9)
     hi_c = jnp.maximum(hi, 1e-9)
-    bgrad = (1.0 / barrier_t) * (
-        jnp.einsum("bmn,btm->btn", prob.K, 1.0 / hi_c)
-        - jnp.einsum("bmn,btm->btn", prob.K, 1.0 / lo_c))
+    KT = partial(jnp.einsum, "bmn,btm->btn", prob.K,
+                 precision=CONSTRAINT_PRECISION)
+    bgrad = (1.0 / barrier_t) * (KT(1.0 / hi_c) - KT(1.0 / lo_c))
     vlo = jnp.maximum(-lo, 0.0)
     vhi = jnp.maximum(-hi, 0.0)
-    qgrad = penalty_w * 2.0 * (jnp.einsum("bmn,btm->btn", prob.K, vhi)
-                               - jnp.einsum("bmn,btm->btn", prob.K, vlo))
+    qgrad = penalty_w * 2.0 * (KT(vhi) - KT(vlo))
     return bgrad, qgrad
 
 
@@ -341,12 +343,12 @@ def solve_fleet(
         batch = stack_problems(list(fleet))
         prob = batch.problem
     cfg = cfg or SolverConfig()
-    on_tpu = jax.default_backend() == "tpu"
+    # the kernel is the default exactly where it compiles (TPU); elsewhere
+    # it would run in the Pallas interpreter, so vmap is the default there
+    interpret = resolve_interpret(interpret)
     if hot_loop is None:
-        hot_loop = "kernel" if on_tpu else "vmap"
+        hot_loop = "vmap" if resolve_interpret() else "kernel"
     assert hot_loop in ("vmap", "kernel", "ref"), hot_loop
-    if interpret is None:
-        interpret = not on_tpu
     if starts is None:
         if batch is not None:
             # per-tenant starts at TRUE shapes: invariant to how the fleet
@@ -356,7 +358,7 @@ def solve_fleet(
         else:
             starts = jax.vmap(lambda pb: make_starts(pb, n_starts, seed))(prob)
     return _solve_fleet_impl(prob, jnp.asarray(starts), cfg, hot_loop,
-                             bool(interpret))
+                             interpret)
 
 
 def make_fleet_starts(batch: FleetBatch, n_starts: int,
@@ -457,6 +459,7 @@ class FleetStepResult(NamedTuple):
     iters: jnp.ndarray     # (B,) adaptive-PGD iterations per lane
     trace: Optional[PGDTrace] = None  # (B, steps) per-lane convergence rows
     deadline_hit: Optional[bool] = None  # anytime tick truncated (None: n/a)
+    anytime: Optional[AnytimeReport] = None  # the anytime drive's report
 
 
 @partial(jax.jit, static_argnames=("steps",))
@@ -576,8 +579,10 @@ def solve_fleet_step(
     ``deadline_ms`` set) runs the tick chunked against the injectable
     clock and returns each lane's best-so-far feasible iterate when the
     fleet-wide budget expires, with ``FleetStepResult.deadline_hit``
-    reporting the truncation; a disabled/absent config takes the exact
-    pre-anytime program (Python-level branch — bit-identical results)."""
+    reporting the truncation and ``FleetStepResult.anytime`` the whole
+    :class:`repro.core.pgd.AnytimeReport`; a disabled/absent config takes
+    the exact pre-anytime program (Python-level branch — bit-identical
+    results)."""
     prob = fleet.problem if isinstance(fleet, FleetBatch) else fleet
     if active is None and isinstance(fleet, FleetBatch):
         active = fleet.active_mask
@@ -600,6 +605,6 @@ def solve_fleet_step(
             cfg, anytime)
         res = _step_fleet_anytime_finalize_impl(prob, state.x_best, x_current,
                                                 active, state.it)
-        return res._replace(deadline_hit=report.deadline_hit)
+        return res._replace(deadline_hit=report.deadline_hit, anytime=report)
     impl = _step_fleet_traced_impl if capture_trace else _step_fleet_impl
     return impl(prob, x_current, delta_max, x_init, active, int(steps))

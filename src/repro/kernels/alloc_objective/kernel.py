@@ -27,10 +27,13 @@ gradient back to n columns.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _objective_math(x, K, E, c, d, scal):
@@ -100,7 +103,7 @@ def _fleet_kernel(x_ref, k_ref, e_ref, c_ref, d_ref, scal_ref, f_ref, g_ref):
 
 
 def alloc_objective_pallas(X, K, E, c, d, scalars, *, block_s: int = 128,
-                           interpret: bool = True):
+                           interpret: Optional[bool] = None):
     """X (S, n_pad); K (m, n_pad); E (p, n_pad); c (n_pad,); d (m,);
     scalars (8,) f32. Returns (f (S,), grad (S, n_pad))."""
     S, n = X.shape
@@ -127,14 +130,15 @@ def alloc_objective_pallas(X, K, E, c, d, scalars, *, block_s: int = 128,
             jax.ShapeDtypeStruct((S, 1), jnp.float32),
             jax.ShapeDtypeStruct((S, n), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(X, K, E, c[None, :], d[None, :], scalars[None, :])
     f, g = out
     return f[:, 0], g
 
 
 def alloc_objective_fleet_pallas(X, K, E, c, d, scalars, *,
-                                 block_t: int = 128, interpret: bool = True):
+                                 block_t: int = 128,
+                                 interpret: Optional[bool] = None):
     """Fleet (multi-tenant) batch: per-problem matrices indexed by the grid.
 
     X (B, T, n_pad); K (B, m, n_pad); E (B, p, n_pad); c (B, n_pad);
@@ -165,7 +169,7 @@ def alloc_objective_fleet_pallas(X, K, E, c, d, scalars, *,
             jax.ShapeDtypeStruct((B, T, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, T, n), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(X, K, E, c[:, None, :], d[:, None, :], scalars[:, None, :])
     f, g = out
     return f[:, :, 0], g

@@ -1,10 +1,12 @@
 """Public wrapper: pads n to the 128-lane boundary and the start batch to the
-block size, dispatches to the Pallas kernel, slices back. ``interpret=True``
-on CPU (validation); on TPU pass interpret=False for the compiled kernel.
+block size, dispatches to the Pallas kernel, slices back. ``interpret=None``
+compiles the kernel on TPU and interprets it elsewhere
+(:func:`repro.kernels.resolve_interpret`).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +27,8 @@ def _pad_to(x, mult, axis):
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def batched_value_and_grad(prob: AllocationProblem, X: jnp.ndarray,
-                           block_s: int = 128, interpret: bool = True):
+                           block_s: int = 128,
+                           interpret: Optional[bool] = None):
     """(f (S,), grad (S, n)) for a batch of allocations X (S, n)."""
     S, n = X.shape
     Xp = _pad_to(_pad_to(X.astype(jnp.float32), 128, 1), block_s, 0)
@@ -43,7 +46,7 @@ def batched_value_and_grad(prob: AllocationProblem, X: jnp.ndarray,
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret", "use_kernel"))
 def fleet_value_and_grad(prob: AllocationProblem, X: jnp.ndarray,
-                         block_t: int = 128, interpret: bool = True,
+                         block_t: int = 128, interpret: Optional[bool] = None,
                          use_kernel: bool = True):
     """(f (B, T), grad (B, T, n)) for a fleet batch.
 

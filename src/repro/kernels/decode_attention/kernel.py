@@ -12,10 +12,13 @@ VMEM per program ~ 2*bk*dh f32 + bk scores: bk=512, dh=128 -> ~0.6MB.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -54,7 +57,7 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, acc_ref, m_ref, l_ref,
 
 
 def decode_attention_pallas(q, k_cache, v_cache, valid, *, block_k: int = 512,
-                            interpret: bool = True):
+                            interpret: Optional[bool] = None):
     """q (B, 1, H, dh); k/v (B, G, S, dh); valid (S,) bool/int.
     Returns (B, 1, H, dh)."""
     B, _, H, dh = q.shape
@@ -86,5 +89,5 @@ def decode_attention_pallas(q, k_cache, v_cache, valid, *, block_k: int = 512,
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k_cache, v_cache, valid_i)

@@ -21,10 +21,13 @@ issued).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -80,7 +83,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 
 
 def flash_attention_pallas(q, k, v, *, window: int = 0, block_q: int = 128,
-                           block_k: int = 128, interpret: bool = True):
+                           block_k: int = 128,
+                           interpret: Optional[bool] = None):
     """q (B, S, H, dh); k/v (B, S, G, dh) -> (B, S, H, dh)."""
     B, S, H, dh = q.shape
     G = k.shape[2]
@@ -111,5 +115,5 @@ def flash_attention_pallas(q, k, v, *, window: int = 0, block_q: int = 128,
             pltpu.VMEM((bq, 1), jnp.float32),    # running max
             pltpu.VMEM((bq, 1), jnp.float32),    # running denom
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

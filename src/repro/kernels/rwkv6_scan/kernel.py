@@ -17,10 +17,13 @@ exp(60) — contributions beyond that decay window are below f32 resolution).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sf_ref,
@@ -71,7 +74,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sf_ref,
 
 
 def rwkv6_scan_pallas(r, k, v, w, u, s0, *, chunk: int = 64,
-                      interpret: bool = True):
+                      interpret: Optional[bool] = None):
     """r,k,v,w (B, S, H, hs); u (H, hs); s0 (B, H, hs, hs).
     Returns (y (B, S, H, hs), s_final (B, H, hs, hs))."""
     B, S, H, hs = r.shape
@@ -101,6 +104,6 @@ def rwkv6_scan_pallas(r, k, v, w, u, s0, *, chunk: int = 64,
             jax.ShapeDtypeStruct((B, H, hs, hs), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hs, hs), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(r, k, v, w, u, s0)
     return y, sf

@@ -24,7 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import get_config, list_archs            # noqa: E402
 from repro.distributed import sharding as shd                # noqa: E402
-from repro.launch.mesh import make_production_mesh, mesh_context  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.shapes import (SHAPES, batch_axes, cell_applicable,  # noqa: E402
                                  input_specs, ruleset_name)
 from repro.launch.steps import (make_decode_step, make_prefill_step,   # noqa: E402
@@ -140,7 +140,7 @@ def _compile_step(cfg, shape, mesh, rules):
                          donate_argnums=(1,))
         args = (params_sds, inputs["caches"], inputs["tokens"], inputs["pos"])
 
-    with mesh_context(mesh), shd.use_rules(rules):
+    with jax.set_mesh(mesh), shd.use_rules(rules):
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()
@@ -150,8 +150,6 @@ def _compile_step(cfg, shape, mesh, rules):
 
 def _cost_terms(compiled):
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):      # jax 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     coll = parse_collective_bytes(compiled.as_text())
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),
@@ -230,8 +228,6 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
 
     compiled, t_lower, t_compile = _compile_step(cfg, shape, mesh, rules)
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):      # jax 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     coll = parse_collective_bytes(hlo)

@@ -1,36 +1,20 @@
 """Production mesh builders. Functions, NOT module-level constants — importing
-this module never touches jax device state."""
+this module never touches jax device state. Enter a mesh with
+``jax.set_mesh(mesh)``."""
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` appeared in jax 0.5.x; older jax (0.4.37 in the image)
-    has neither ``jax.sharding.AxisType`` nor the ``make_mesh`` kwarg — all
-    axes are implicitly Auto there, so omitting it is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (tests, examples, elastic rescale)."""
+    """Arbitrary mesh (tests, examples, elastic rescale); every axis Auto."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where it exists (jax >= 0.5.x); on older jax
-    the Mesh object itself is the context manager with the same effect."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+                         axis_types=(AxisType.Auto,) * len(axes))

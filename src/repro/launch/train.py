@@ -22,7 +22,7 @@ from repro.checkpoint import checkpoint as ckpt
 from repro.configs import get_config, list_archs
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.distributed import sharding as shd
-from repro.launch.mesh import make_mesh, mesh_context
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step
 from repro.models import abstract_params, init_model, split
 from repro.optim import adamw
@@ -73,7 +73,7 @@ def main():
                                   global_batch=args.batch, seed=0))
     saver = ckpt.AsyncCheckpointer(args.ckpt_dir, keep=2)
     t0 = time.time()
-    with mesh_context(mesh), shd.use_rules(rules):
+    with jax.set_mesh(mesh), shd.use_rules(rules):
         for step in range(args.steps):
             b = data.global_batch(step)
             batch = {k: jax.device_put(jnp.asarray(v), batch_sh[k])

@@ -7,9 +7,10 @@ platform string, CPU count, UTC timestamp and the CLI args the run was
 invoked with. Bench trajectories across PRs and machines are only
 comparable when this block says they are.
 
-Everything degrades to ``None`` rather than raising (e.g. git absent, or
-running from an sdist without a work tree): provenance must never be the
-reason a benchmark fails.
+Git facts degrade to ``None`` rather than raising (git absent, or running
+from an sdist without a work tree). JAX facts do not: a JAX that cannot
+start is not a backend to stamp, so its error propagates and the bench
+fails instead of recording ``backend: None`` and running on.
 
 The block also carries a **config digest** (:func:`config_digest` — a
 sha256 over the run's canonicalized configuration: bench args + solver
@@ -92,19 +93,13 @@ def provenance_block(argv: Optional[List[str]] = None,
     ``bench_compare`` can refuse cross-config comparisons; ``seeds`` the
     RNG seeds the run consumed. Both stamp ``None`` when omitted (older
     BENCH files simply lack the keys)."""
-    try:
-        import jax
-        import jaxlib
-        jax_version = jax.__version__
-        jaxlib_version = jaxlib.__version__
-        backend = jax.default_backend()
-    except Exception:  # jax import/init failure — stamp what we can
-        jax_version = jaxlib_version = backend = None
+    import jax
+    import jaxlib
     return {
         "git_sha": git_sha(),
-        "jax": jax_version,
-        "jaxlib": jaxlib_version,
-        "backend": backend,
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "backend": jax.default_backend(),
         "platform": platform.platform(),
         "python": sys.version.split()[0],
         "cpu_count": os.cpu_count(),

@@ -14,6 +14,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from repro.compile_cache import setup_compile_cache
 from repro.core.catalog import make_cloud_catalog
 from repro.fleet.traces import flash_crowd_trace
 from repro.obs.health import HealthMonitor
@@ -82,7 +83,8 @@ def run_demo(lanes: int = 8, ticks: int = 24,
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
     """CLI entry point: ``python -m repro.serve [--lanes N] [--ticks T]
-    [--deadline-ms MS] [--seed S]``."""
+    [--deadline-ms MS] [--seed S]``. Turns on the persistent compile cache
+    (:mod:`repro.compile_cache`) before the first compile."""
     ap = argparse.ArgumentParser(
         prog="python -m repro.serve", description=__doc__,
         formatter_class=argparse.RawDescriptionHelpFormatter)
@@ -94,6 +96,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     help="enforced per-tick wall budget (default: none)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    setup_compile_cache()
     run_demo(lanes=args.lanes, ticks=args.ticks,
              deadline_ms=args.deadline_ms, seed=args.seed)
     return 0

@@ -52,7 +52,7 @@ import numpy as np
 from repro.core.catalog import Catalog
 from repro.core.controller import (ControllerStep,
                                    InfrastructureOptimizationController)
-from repro.core.pgd import AnytimeConfig
+from repro.core.pgd import AnytimeConfig, AnytimeReport
 from repro.core.problem import PenaltyParams
 from repro.fleet.batching import stack_problems
 from repro.fleet.solver import solve_fleet_step
@@ -147,7 +147,12 @@ class ServeEngine:
     and the anytime driver (deterministic tests inject a fake);
     ``health`` — optional :class:`repro.obs.HealthMonitor` observing every
     decision and tick (compile ticks excluded from its deadline budget via
-    the serve tick's compile key)."""
+    the serve tick's compile key).
+
+    ``last_anytime`` holds the most recent tick's
+    :class:`repro.core.pgd.AnytimeReport` (None when that tick ran no
+    budgeted warm solve): its ``elapsed_ms`` stays within the warm solve's
+    budget plus ``max_step_ms``, one chunk."""
 
     def __init__(self, catalog: Catalog, capacity: int, *,
                  deadline_ms: Optional[float] = None,
@@ -172,6 +177,7 @@ class ServeEngine:
         self.health = health
         self.tick_count = 0
         self.records: List[DecisionRecord] = []
+        self.last_anytime: Optional[AnytimeReport] = None
         self._lanes = [_Lane() for _ in range(self.capacity)]
         self._by_name: Dict[str, int] = {}
         # free lanes hold this placeholder problem so the stacked batch
@@ -255,6 +261,7 @@ class ServeEngine:
         counter."""
         t = self.tick_count
         self.tick_count += 1
+        self.last_anytime = None
         t0 = self.clock()
         records: List[DecisionRecord] = []
 
@@ -328,14 +335,16 @@ class ServeEngine:
             res = solve_fleet_step(batch, X_cur, self.delta_max,
                                    steps=self.solver_steps, anytime=anytime)
         hit = bool(res.deadline_hit or False)
+        self.last_anytime = res.anytime
         X_int = np.asarray(res.x_int, np.float64)
+        X_rel = np.asarray(res.x, np.float64)
         lane_iters = np.asarray(res.iters, np.int64)
         for i in warm:
             ln = self._lanes[i]
             step = ln.controller.apply_counts(
                 demands[i], X_int[i], replanned=False,
                 solver_iters=int(lane_iters[i]), deadline_hit=hit)
-            ln.controller.last_x_rel = np.asarray(res.x, np.float64)[i]
+            ln.controller.last_x_rel = X_rel[i]
             records.append(self._record(t, i, ln, step, t0))
 
     def _record(self, t: int, lane: int, ln: _Lane, step: ControllerStep,

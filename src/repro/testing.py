@@ -8,9 +8,10 @@ Two things live here:
   imports, which pytest's rootdir-based collection forbids).
 
 * a small, deterministic property-test core standing in for the parts of
-  ``hypothesis`` the test suite uses. The container image does not ship
-  hypothesis; tests import it with a fallback to this shim so property tests
-  still sweep a deterministic sample of the input space instead of being
+  ``hypothesis`` the test suite uses. Tests import hypothesis (installed
+  with jax 0.9.0; ``tests/conftest.py`` loads its one settings profile)
+  and fall back to this shim where it is missing, so property tests still
+  sweep a deterministic sample of the input space instead of being
   skipped wholesale. The shim's contract (all test-enforced in
   ``tests/test_testing_shim.py``):
 

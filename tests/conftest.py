@@ -10,6 +10,20 @@ from repro.testing import make_toy_problem  # canonical home (rootdir-safe)
 
 jax.config.update("jax_enable_x64", False)
 
+# One hypothesis profile for the whole suite: no per-example deadline (the
+# first example of a property test pays its JIT compile), derandomized
+# draws so a run reproduces, and no example database on disk. Per-test
+# @settings only choose max_examples. Without hypothesis the tests fall
+# back to the deterministic shim in repro.testing.
+try:
+    from hypothesis import settings as _hypothesis_settings
+except ImportError:
+    pass
+else:
+    _hypothesis_settings.register_profile(
+        "repro", deadline=None, derandomize=True, database=None)
+    _hypothesis_settings.load_profile("repro")
+
 
 @pytest.fixture(scope="session")
 def toy_problem():

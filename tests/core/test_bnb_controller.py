@@ -46,6 +46,31 @@ def test_controller_churn_bounded():
     assert second.churn <= 5.0 + 8.0  # delta + rounding slack
 
 
+
+@pytest.mark.parametrize("allowed_idx", [None, np.array([3, 7])])
+def test_controller_commit_covers_f32_near_miss_in_float64(allowed_idx):
+    """Counts that cover the demand only to within f32 error are committed
+    with one more unit, of a type the tenant may use; counts really short
+    of the demand are committed as they are, and reported short."""
+    from repro.core import Catalog, make_cloud_catalog
+    cat = Catalog(make_cloud_catalog().instances[::40])
+    K = cat.matrices()[0].astype(np.float64)
+    counts = np.zeros(cat.n)
+    counts[5] = 3.0
+    provided = K @ counts
+    ctl = InfrastructureOptimizationController(catalog=cat,
+                                               allowed_idx=allowed_idx)
+    step = ctl.apply_counts(provided * (1 + 5e-7), counts, replanned=True)
+    assert step.metrics.satisfied
+    added = np.flatnonzero(step.counts - counts)
+    assert step.counts.sum() == 4.0 and len(added) == 1
+    if allowed_idx is not None:
+        assert added[0] in allowed_idx
+    np.testing.assert_array_equal(ctl.x_current, step.counts)
+    short = ctl.apply_counts(provided * 2.0, counts, replanned=False)
+    assert not short.metrics.satisfied
+    np.testing.assert_array_equal(short.counts, counts)
+
 @pytest.mark.slow
 def test_controller_failure_replan():
     from repro.core import Catalog, make_cloud_catalog

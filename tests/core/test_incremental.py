@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 try:
     from hypothesis import given, settings, strategies as st
-except ImportError:  # container image has no hypothesis — deterministic shim
+except ImportError:  # hypothesis not installed — deterministic shim
     from repro.testing import given, settings, strategies as st
 
 from repro.core import project_l1_ball, project_incremental, solve_incremental
@@ -13,7 +13,7 @@ from repro.testing import make_toy_problem
 
 
 @pytest.mark.slow
-@settings(max_examples=50, deadline=None)
+@settings(max_examples=50)
 @given(seed=st.integers(0, 10_000), radius=st.floats(0.1, 20.0), dim=st.integers(2, 40))
 def test_l1_projection_properties(seed, radius, dim):
     rng = np.random.default_rng(seed)
@@ -29,7 +29,7 @@ def test_l1_projection_properties(seed, radius, dim):
         np.testing.assert_allclose(np.asarray(w), np.asarray(v), atol=1e-6)
 
 
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25)
 @given(seed=st.integers(0, 10_000))
 def test_l1_projection_is_closest_point(seed):
     """Projection must beat random candidates inside the ball on distance."""

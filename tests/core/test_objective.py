@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 try:
     from hypothesis import given, settings, strategies as st
-except ImportError:  # container image has no hypothesis — deterministic shim
+except ImportError:  # hypothesis not installed — deterministic shim
     from repro.testing import given, settings, strategies as st
 
 import repro.core.objective as obj
@@ -87,7 +87,7 @@ def test_consolidation_term_bounds(toy_problem):
                                float(P.alpha) * p, rtol=1e-4)
 
 
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25)
 @given(seed=st.integers(0, 10_000), scale=st.floats(0.1, 10.0))
 def test_objective_finite_and_grad_consistent(seed, scale):
     prob = make_toy_problem(seed=seed, demand_scale=scale)

@@ -139,3 +139,17 @@ def test_run_anytime_requires_a_deadline():
     with pytest.raises(ValueError):
         run_anytime(lambda: None, lambda s, e: s, PGDConfig(),
                     AnytimeConfig(deadline_ms=None))
+
+
+@pytest.mark.parametrize("budget_ms", [0.5, 3.0, 20.0])
+def test_report_elapsed_within_budget_plus_one_step(budget_ms):
+    """Every chunk is fenced before the clock is read, so a drive overruns
+    its budget by at most its longest step (the init or one chunk)."""
+    prob, x_cur, delta = _warm_setup()
+    _, _, report = solve_incremental_info(
+        prob, x_cur, delta,
+        anytime=AnytimeConfig(deadline_ms=budget_ms, chunk_iters=8,
+                              clock=_fake_clock(1.0)))
+    assert report.budget_ms == budget_ms
+    assert report.max_step_ms > 0.0
+    assert report.elapsed_ms <= report.budget_ms + report.max_step_ms

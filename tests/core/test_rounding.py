@@ -4,11 +4,12 @@ import jax.numpy as jnp
 import numpy as np
 try:
     from hypothesis import given, settings, strategies as st
-except ImportError:  # container image has no hypothesis — deterministic shim
+except ImportError:  # hypothesis not installed — deterministic shim
     from repro.testing import given, settings, strategies as st
 
 import repro.core.objective as obj
 from repro.core import greedy_round, round_and_polish, scale_down, solve_relaxation, SolverConfig
+from repro.core.rounding import cover_in_float64
 from repro.testing import make_toy_problem
 
 
@@ -43,7 +44,7 @@ def test_scale_down_keeps_feasibility(toy_problem):
     assert float(jnp.sum(xd)) <= float(jnp.sum(x))
 
 
-@settings(max_examples=20, deadline=None)
+@settings(max_examples=20)
 @given(seed=st.integers(0, 10_000))
 def test_rounding_properties(seed):
     prob = make_toy_problem(seed=seed)
@@ -60,7 +61,7 @@ def test_rounding_properties(seed):
     assert _covers(prob, x)
 
 
-@settings(max_examples=15, deadline=None)
+@settings(max_examples=15)
 @given(seed=st.integers(0, 10_000))
 def test_scale_down_properties(seed):
     prob = make_toy_problem(seed=seed)
@@ -70,3 +71,22 @@ def test_scale_down_properties(seed):
     assert np.allclose(xd, np.round(xd))
     # removal is monotone: no count increased
     assert np.all(xd <= 5.0 + 1e-6)
+
+
+
+def test_cover_in_float64_closes_only_f32_sized_gaps():
+    """Two units of a type holding just under half the demand come within
+    5e-7 of it (relative), inside the f32 error of K x where rounding
+    stops, yet fall 5e-4 short in float64 raw units, where
+    ``repro.core.metrics`` checks coverage to 1e-6. The
+    float64 top-up adds the best unit per dollar among the addable types; a
+    covering allocation, or one really short, is left alone."""
+    K = np.array([[1e3 * (0.5 - 2.5e-7), 1e3]])
+    c, d = np.array([1.0, 3.0]), np.array([1e3])
+    both = np.array([True, True])
+    near = cover_in_float64(K, c, both, [2.0, 0.0], d)
+    assert near.tolist() == [3.0, 0.0] and float(K[0] @ near) >= d[0]
+    assert cover_in_float64(K, c, both, [3.0, 0.0], d).tolist() == [3.0, 0.0]
+    assert cover_in_float64(K, c, both, [1.0, 0.0], d).tolist() == [1.0, 0.0]
+    only_b = cover_in_float64(K, c, np.array([False, True]), [2.0, 0.0], d)
+    assert only_b.tolist() == [2.0, 1.0]

@@ -4,7 +4,7 @@ import numpy as np
 import pytest
 try:
     from hypothesis import given, settings, strategies as st
-except ImportError:  # container image has no hypothesis — deterministic shim
+except ImportError:  # hypothesis not installed — deterministic shim
     from repro.testing import given, settings, strategies as st
 
 import repro.core.objective as obj
@@ -68,7 +68,7 @@ def test_multistart_picks_best(toy_problem):
     assert float(ms.best.fun) <= np.min(merit) + 1e-5
 
 
-@settings(max_examples=10, deadline=None)
+@settings(max_examples=10)
 @given(seed=st.integers(0, 1000))
 def test_solver_feasible_property(seed):
     prob = make_toy_problem(seed=seed)

@@ -7,7 +7,7 @@ when the image lacks hypothesis.
 """
 try:
     from hypothesis import given, settings, strategies as st
-except ImportError:  # container image has no hypothesis — deterministic shim
+except ImportError:  # hypothesis not installed — deterministic shim
     from repro.testing import given, settings, strategies as st
 
 import pytest

@@ -18,7 +18,7 @@ share — so the comparison cannot drift from the implementation.
 """
 try:
     from hypothesis import given, settings, strategies as st
-except ImportError:  # container image has no hypothesis — deterministic shim
+except ImportError:  # hypothesis not installed — deterministic shim
     from repro.testing import given, settings, strategies as st
 
 import pytest

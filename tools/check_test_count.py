@@ -20,7 +20,7 @@ import subprocess
 import sys
 from pathlib import Path
 
-DEFAULT_MIN = 401  # ratcheted at ISSUE 10 (anytime/serve suites); 374 at ISSUE 9; 312 at ISSUE 8; 262 at introduction (ISSUE 7)
+DEFAULT_MIN = 416  # the collected count at the last ratchet; raise it with new tests
 
 
 def main() -> int:
