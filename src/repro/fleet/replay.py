@@ -491,7 +491,9 @@ def _replay_fleet_batched(catalog: Catalog, tenants: Sequence[TenantSpec], *,
     a sequential replay of that tenant alone.
 
     Telemetry (``repro.obs``): each tick is a ``replay/tick`` span wrapping
-    per-bucket ``replay/stack`` / ``replay/solve`` / ``replay/round`` spans;
+    a ``replay/build`` span (every tenant's problem) and per-bucket
+    ``replay/stack`` (stacking, start points, churn bounds) /
+    ``replay/solve`` / ``replay/round`` spans;
     solve spans carry a compile key per (program, bucket shape) so first
     calls are tagged as compile time. A :class:`HealthMonitor` additionally
     observes every committed (tenant, tick) — counts, relaxed solution for
@@ -524,9 +526,10 @@ def _replay_fleet_batched(catalog: Catalog, tenants: Sequence[TenantSpec], *,
         with span("replay/tick", cat="replay", tick=t, engine="batched",
                   controller="myopic", compile_key=tick_key):
             tick_iters = 0
-            for b, ctl in enumerate(ctls):
-                if t < T_len[b]:
-                    probs[b] = ctl.make_problem(traces[b][t])
+            with span("replay/build", cat="replay"):
+                for b, ctl in enumerate(ctls):
+                    if t < T_len[b]:
+                        probs[b] = ctl.make_problem(traces[b][t])
             for key, idx in sorted(groups.items()):
                 n_pad, m_pad, p_pad, n_starts = key
                 active = T_len[idx] > t                 # (Bk,) liveness
@@ -536,6 +539,16 @@ def _replay_fleet_batched(catalog: Catalog, tenants: Sequence[TenantSpec], *,
                     batch = stack_problems([probs[b] for b in idx],
                                            n_max=n_pad, m_max=m_pad,
                                            p_max=p_pad, active=active)
+                    if t > 0:
+                        X_cur = embed_solutions(
+                            batch, [ctls[b].x_current for b in idx])
+                        X_init = None
+                        if (warm_start == "relaxed"
+                                and x_rel_prev[idx[0]] is not None):
+                            X_init = embed_solutions(
+                                batch, [x_rel_prev[b] for b in idx])
+                        delta = np.asarray(
+                            [tenants[b].delta_max for b in idx], np.float32)
                 if t == 0:
                     # cold start: one batched multistart solve for the
                     # bucket, per-tenant starts drawn at true shape (seed 0,
@@ -553,15 +566,6 @@ def _replay_fleet_batched(catalog: Catalog, tenants: Sequence[TenantSpec], *,
                     tick_iters += int(res.iters)
                     bucket_hit = False
                 else:
-                    X_cur = embed_solutions(
-                        batch, [ctls[b].x_current for b in idx])
-                    X_init = None
-                    if (warm_start == "relaxed"
-                            and x_rel_prev[idx[0]] is not None):
-                        X_init = embed_solutions(
-                            batch, [x_rel_prev[b] for b in idx])
-                    delta = np.asarray([tenants[b].delta_max for b in idx],
-                                       np.float32)
                     with span("replay/solve", cat="replay", bucket=str(key),
                               compile_key=("solve_fleet_step", key, len(idx),
                                            capture_solver_trace,

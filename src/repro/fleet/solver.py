@@ -38,6 +38,7 @@ from repro.core.solver import SolverConfig, phase1_point, solve_relaxation
 from repro.kernels import resolve_interpret
 from repro.kernels.alloc_objective.ops import fleet_value_and_grad
 from repro.kernels.alloc_objective.ref import alloc_objective_fleet_value
+from repro.obs.telemetry import span
 
 from .batching import (BucketedFleet, FleetBatch, bucket_problems,
                        scatter_from_buckets, stack_problems, tenant_problem)
@@ -582,7 +583,9 @@ def solve_fleet_step(
     reporting the truncation and ``FleetStepResult.anytime`` the whole
     :class:`repro.core.pgd.AnytimeReport`; a disabled/absent config takes
     the exact pre-anytime program (Python-level branch — bit-identical
-    results)."""
+    results). While telemetry records, the anytime branch is two spans:
+    ``fleet/anytime`` (the chunked drive) and ``fleet/round`` (rounding,
+    masking and scoring, fenced on ``x_int``)."""
     prob = fleet.problem if isinstance(fleet, FleetBatch) else fleet
     if active is None and isinstance(fleet, FleetBatch):
         active = fleet.active_mask
@@ -597,14 +600,20 @@ def solve_fleet_step(
             raise ValueError("anytime deadlines and capture_trace are "
                              "mutually exclusive; drop one")
         cfg = PGDConfig(max_iters=int(steps))
-        state, report = run_anytime(
-            lambda: _step_fleet_anytime_init_impl(prob, x_current, delta_max,
-                                                  x_init, cfg),
-            lambda s, e: _step_fleet_anytime_chunk_impl(prob, x_current,
-                                                        delta_max, s, e, cfg),
-            cfg, anytime)
-        res = _step_fleet_anytime_finalize_impl(prob, state.x_best, x_current,
-                                                active, state.it)
+        # run_anytime ends on a host sync of the last chunk (its done-check
+        # or its final fence), so the span needs no fence of its own
+        with span("fleet/anytime", cat="fleet"):
+            state, report = run_anytime(
+                lambda: _step_fleet_anytime_init_impl(prob, x_current,
+                                                      delta_max, x_init, cfg),
+                lambda s, e: _step_fleet_anytime_chunk_impl(
+                    prob, x_current, delta_max, s, e, cfg),
+                cfg, anytime)
+        with span("fleet/round", cat="fleet") as sp:
+            res = _step_fleet_anytime_finalize_impl(prob, state.x_best,
+                                                    x_current, active,
+                                                    state.it)
+            sp.fence(res.x_int)
         return res._replace(deadline_hit=report.deadline_hit, anytime=report)
     impl = _step_fleet_traced_impl if capture_trace else _step_fleet_impl
     return impl(prob, x_current, delta_max, x_init, active, int(steps))

@@ -12,6 +12,10 @@ measure the system but never participate in it**. Concretely:
   forces *completion*, never *recomputation*: device values are untouched,
   so per-tenant integer allocations are bit-identical with telemetry on or
   off (test-enforced in ``tests/obs/test_telemetry.py``).
+* A recording span is also a ``jax.profiler.TraceAnnotation`` of the same
+  name, so under a running profiler the program's spans land in the
+  profiler's own trace, on its clock, beside the device operations they
+  wait for.
 
 Span model
 ----------
@@ -170,22 +174,26 @@ class Recorder:
         ``compile_key`` (hashable) tags this span ``phase="compile"`` the
         first time the key is seen by this recorder, ``"execute"`` after.
         ``fence`` optionally names a pytree to ``block_until_ready`` at
-        close (equivalent to calling ``Span.fence`` last)."""
+        close (equivalent to calling ``Span.fence`` last). The span is
+        also a ``jax.profiler.TraceAnnotation`` named ``name``."""
         phase = None
         if compile_key is not None:
             first = compile_key not in self._seen_keys
             if first:
                 self._seen_keys.add(compile_key)
             phase = "compile" if first else "execute"
-        sp = Span(self, name, cat, dict(tags), self._depth, phase)
-        self._depth += 1
-        try:
-            yield sp
-        finally:
-            if fence is not None:
-                sp.fence(fence)
-            self._depth -= 1
-            sp._close()
+        from jax import profiler
+
+        with profiler.TraceAnnotation(name):
+            sp = Span(self, name, cat, dict(tags), self._depth, phase)
+            self._depth += 1
+            try:
+                yield sp
+            finally:
+                if fence is not None:
+                    sp.fence(fence)
+                self._depth -= 1
+                sp._close()
 
     def counter(self, name: str, inc: float = 1.0) -> None:
         """Add ``inc`` to the monotonic counter ``name``."""

@@ -129,6 +129,7 @@ class _Lane:
     controller: Optional[InfrastructureOptimizationController] = None
     name: Optional[str] = None
     pending: Optional[np.ndarray] = None      # latest unserved demand
+    pending_since: Optional[float] = None     # clock when pending was set
     prob: Optional[object] = None             # lane's current problem
     last_update_tick: int = -1
     joined_tick: int = -1
@@ -211,10 +212,10 @@ class ServeEngine:
         # fresh controller = fresh warm-start lineage: nothing of the
         # departed tenant's state leaks into the joiner's solves
         self._lanes[i] = _Lane(controller=self._make_controller(), name=name,
-                               pending=(None if demand is None
-                                        else np.asarray(demand, np.float64)),
                                prob=self._lanes[i].prob,
                                joined_tick=self.tick_count, needs_cold=True)
+        if demand is not None:
+            self._queue(self._lanes[i], demand)
         self._by_name[name] = i
         return i
 
@@ -230,8 +231,17 @@ class ServeEngine:
         """Queue ``name``'s latest demand (latest-wins: a tenant that
         submits twice between ticks is served its NEWEST demand — serving
         a superseded demand would spend the budget on a stale answer)."""
-        i = self._require(name)
-        self._lanes[i].pending = np.asarray(demand, np.float64)
+        self._queue(self._lanes[self._require(name)], demand)
+
+    def _queue(self, ln: _Lane, demand: np.ndarray) -> None:
+        if ln.pending is None:
+            ln.pending_since = self.clock()
+        ln.pending = np.asarray(demand, np.float64)
+
+    @staticmethod
+    def _take(ln: _Lane) -> np.ndarray:
+        demand, ln.pending, ln.pending_since = ln.pending, None, None
+        return demand
 
     def tenants(self) -> List[str]:
         """Currently registered tenant names (lane order)."""
@@ -258,7 +268,15 @@ class ServeEngine:
         ``self.records``). Lanes with no fresh demand keep their
         allocation and age their staleness; an empty tick (no pending
         demand anywhere) is a cheap no-op that still advances the tick
-        counter."""
+        counter.
+
+        While telemetry records, the tick is a ``serve/tick`` span with
+        children ``serve/cold`` (per joiner), ``serve/build``,
+        ``serve/stack``, ``serve/solve`` and ``serve/apply``, tagged with
+        the lanes decided, the cold joins, the anytime chunks, the most
+        PGD iterations of a decided lane, the truncation, and each taken
+        lane's ``queue_wait_ms``: the tick's start minus the time its
+        pending demand arrived."""
         t = self.tick_count
         self.tick_count += 1
         self.last_anytime = None
@@ -272,27 +290,31 @@ class ServeEngine:
                 and ln.pending is not None]
         tick_key = ("serve_tick", bool(cold), bool(warm))
 
-        with span("serve/tick", cat="serve", tick=t, compile_key=tick_key):
+        with span("serve/tick", cat="serve", tick=t,
+                  compile_key=tick_key) as sp:
+            sp.tag(cold=len(cold), queue_wait_ms=[
+                (t0 - self._lanes[i].pending_since) * 1e3
+                for i in cold + warm])
             # cold joins: per-lane multistart (all lanes share the catalog
             # shape, so every cold join reuses one compiled program)
             for i in cold:
                 ln = self._lanes[i]
-                demand, ln.pending = ln.pending, None
+                demand = self._take(ln)
                 ln.prob = ln.controller.make_problem(demand)
                 with span("serve/cold", cat="serve", tenant=ln.name):
                     step = ln.controller.step(demand)
                 ln.needs_cold = False
-                records.append(self._record(t, i, ln, step, t0, cold=True))
+                records.append(self._record(t, i, ln, step, cold=True))
 
             if warm:
-                self._warm_solve(t, warm, t0, records)
+                self._warm_solve(t, warm, t0, records, sp)
+            sp.tag(lanes=len(records))
 
         dur_ms = (self.clock() - t0) * 1e3
         for rec in records:   # every decision in a tick shares its latency
             rec.latency_ms = dur_ms
         reg = obs_metrics.current_metrics()
         if reg is not None and records:
-            reg.histogram("serve/decision_ms").observe(dur_ms)
             for rec in records:
                 reg.histogram("serve/staleness").observe(rec.staleness)
         if self.health is not None and records:
@@ -301,25 +323,28 @@ class ServeEngine:
         return records
 
     def _warm_solve(self, t: int, warm: List[int], t0: float,
-                    records: List[DecisionRecord]) -> None:
+                    records: List[DecisionRecord], tick_span) -> None:
         """One batched anytime ``solve_fleet_step`` over the lanes holding
         fresh demand; every other lane rides along masked-out so the
         compiled program's shape never changes."""
-        probs, demands = [], {}
-        warm_set = set(warm)
+        demands = {}
         active = np.zeros(self.capacity, bool)
-        X_cur = np.zeros((self.capacity, self.catalog.n), np.float32)
-        for i, ln in enumerate(self._lanes):
-            if i in warm_set:
-                demand, ln.pending = ln.pending, None
-                demands[i] = demand
-                ln.prob = ln.controller.make_problem(demand)
+        with span("serve/build", cat="serve"):
+            for i in warm:
+                ln = self._lanes[i]
+                demands[i] = self._take(ln)
+                ln.prob = ln.controller.make_problem(demands[i])
                 active[i] = True
-            if ln.controller is not None and ln.controller.x_current is not None:
-                X_cur[i] = ln.controller.x_current
-            probs.append(ln.prob if ln.prob is not None
-                         else self._placeholder_prob)
-        batch = stack_problems(probs, active=active)
+        with span("serve/stack", cat="serve"):
+            X_cur = np.zeros((self.capacity, self.catalog.n), np.float32)
+            probs = []
+            for i, ln in enumerate(self._lanes):
+                if (ln.controller is not None
+                        and ln.controller.x_current is not None):
+                    X_cur[i] = ln.controller.x_current
+                probs.append(ln.prob if ln.prob is not None
+                             else self._placeholder_prob)
+            batch = stack_problems(probs, active=active)
         anytime = None
         if self.deadline_ms is not None:
             # the warm solve gets what is LEFT of the tick's budget after
@@ -331,30 +356,35 @@ class ServeEngine:
                 chunk_iters=self.chunk_iters, clock=self.clock)
         with span("serve/solve", cat="serve",
                   compile_key=("serve_solve", self.capacity, self.catalog.n,
-                               anytime is not None)):
+                               anytime is not None)) as sp:
             res = solve_fleet_step(batch, X_cur, self.delta_max,
                                    steps=self.solver_steps, anytime=anytime)
+            sp.fence(res.x_int)
         hit = bool(res.deadline_hit or False)
         self.last_anytime = res.anytime
-        X_int = np.asarray(res.x_int, np.float64)
-        X_rel = np.asarray(res.x, np.float64)
-        lane_iters = np.asarray(res.iters, np.int64)
-        for i in warm:
-            ln = self._lanes[i]
-            step = ln.controller.apply_counts(
-                demands[i], X_int[i], replanned=False,
-                solver_iters=int(lane_iters[i]), deadline_hit=hit)
-            ln.controller.last_x_rel = X_rel[i]
-            records.append(self._record(t, i, ln, step, t0))
+        with span("serve/apply", cat="serve"):
+            X_int = np.asarray(res.x_int, np.float64)
+            X_rel = np.asarray(res.x, np.float64)
+            lane_iters = np.asarray(res.iters, np.int64)
+            for i in warm:
+                ln = self._lanes[i]
+                step = ln.controller.apply_counts(
+                    demands[i], X_int[i], replanned=False,
+                    solver_iters=int(lane_iters[i]), deadline_hit=hit)
+                ln.controller.last_x_rel = X_rel[i]
+                records.append(self._record(t, i, ln, step))
+        tick_span.tag(chunks=None if res.anytime is None
+                      else res.anytime.chunks,
+                      iters=int(lane_iters.max()), truncated=hit)
 
     def _record(self, t: int, lane: int, ln: _Lane, step: ControllerStep,
-                t0: float, cold: bool = False) -> DecisionRecord:
+                cold: bool = False) -> DecisionRecord:
         staleness = (0 if cold or ln.last_update_tick < 0
                      else t - ln.last_update_tick)
         ln.last_update_tick = t
         rec = DecisionRecord(
             tick=t, tenant=ln.name, lane=lane,
-            latency_ms=(self.clock() - t0) * 1e3,   # finalized at tick end
+            latency_ms=0.0,                  # the tick's, set at its end
             deadline_hit=step.deadline_hit,
             solver_iters=step.solver_iters, staleness=staleness,
             feasible=bool(step.metrics.satisfied),

@@ -83,6 +83,40 @@ def test_compile_execute_tagging_and_nesting():
     assert "outer" in rec.summary()
 
 
+def test_span_is_a_trace_annotation_only_while_recording(monkeypatch):
+    """A recording span opens a ``jax.profiler.TraceAnnotation`` of its
+    name around its whole interval, fence included; the disabled path
+    never constructs one."""
+    import jax.profiler
+
+    opened = []
+
+    class FakeAnnotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            opened.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", FakeAnnotation)
+    with span("off"):
+        pass
+    assert opened == []
+    with telemetry() as rec:
+        with span("outer"):
+            with span("inner", fence=np.zeros(2)):
+                pass
+    assert opened == [("enter", "outer"), ("enter", "inner"),
+                      ("exit", "inner"), ("exit", "outer")]
+    assert [e.name for e in rec.events] == ["inner", "outer"]
+    with span("off again"):
+        pass
+    assert len(opened) == 4
+
+
 # ---------------------------------------------------------------------------
 # the contract: telemetry never changes allocations (both engines)
 # ---------------------------------------------------------------------------
@@ -137,3 +171,38 @@ def test_report_degrades_on_empty_recorder():
     rep = ReplayReport.from_recorder(Recorder())
     assert rep.n_ticks == 0 and rep.phases == [] and rep.tick_ms == {}
     assert "0 ticks" in rep.render()
+
+
+@pytest.mark.slow
+def test_serve_bit_identical_with_telemetry_on(tiny_catalog):
+    """The serving engine's decisions are bit-identical with a recorder
+    installed: its spans fence the solve and copy nothing that the
+    untraced tick does not."""
+    from repro.serve import ServeEngine
+
+    def session():
+        ticks = iter(range(10**6))        # 1 ms a reading: never truncates
+        eng = ServeEngine(tiny_catalog, 3, deadline_ms=1e4, chunk_iters=8,
+                          clock=lambda: next(ticks) / 1e3)
+        eng.register("a", demand=BASE)
+        eng.register("b", demand=BASE * 0.5)
+        eng.tick()
+        for k in range(2):
+            eng.submit("a", BASE * (1.2 + 0.3 * k))
+            eng.submit("b", BASE * (0.6 - 0.05 * k))
+            eng.tick()
+        return eng
+
+    plain = session()
+    with telemetry() as rec:
+        instr = session()
+    for name in ("a", "b"):
+        hp = plain._lanes[plain._by_name[name]].controller.history
+        hi = instr._lanes[instr._by_name[name]].controller.history
+        assert len(hp) == len(hi) == 3
+        for sp_, si in zip(hp, hi):
+            np.testing.assert_array_equal(sp_.counts, si.counts)
+            assert sp_.solver_iters == si.solver_iters
+    assert [(r.tenant, r.solver_iters, r.objective) for r in plain.records] \
+        == [(r.tenant, r.solver_iters, r.objective) for r in instr.records]
+    assert len(rec.spans("serve/tick")) == 3

@@ -161,3 +161,59 @@ def test_main_demo_runs(capsys):
     out = capsys.readouterr().out
     assert "latency p50/p99" in out
     assert eng.summary().decisions > 0
+
+
+@pytest.mark.slow
+def test_tick_span_tree_and_queue_wait(catalog):
+    """While telemetry records, a tick is a ``serve/tick`` span whose
+    children cover the tick's parts in order (the anytime solve's own
+    ``fleet/*`` spans one level further down), and whose tags count the
+    lanes and give each taken lane's wait from its first pending demand
+    to the tick's start, on the engine's clock."""
+    from repro.obs import telemetry
+
+    now = SimpleNamespace(t=100.0)
+    eng = ServeEngine(catalog, 3, deadline_ms=1e4, chunk_iters=8,
+                      clock=lambda: now.t)
+    eng.register("a", demand=D0)            # pending since 100.0
+    now.t = 100.5
+    eng.register("b")                       # nothing pending yet
+    now.t = 101.0
+    eng.submit("b", D0 * 0.5)               # pending since 101.0
+    now.t = 102.0
+    with telemetry() as rec:
+        eng.tick()                          # both cold joins
+        now.t = 103.0
+        eng.submit("a", D0 * 1.2)           # pending since 103.0 ...
+        now.t = 103.5
+        eng.submit("a", D0 * 1.3)           # ... coalesced: still 103.0
+        now.t = 104.25
+        recs = eng.tick()                   # a's warm solve
+    assert all(ln.pending_since is None for ln in eng._lanes)
+
+    ticks = rec.spans("serve/tick")
+    assert len(ticks) == 2
+    cold_tick, warm_tick = ticks
+    assert cold_tick.tags["queue_wait_ms"] == pytest.approx([2000.0, 1000.0])
+    assert (cold_tick.tags["cold"], cold_tick.tags["lanes"]) == (2, 2)
+    assert warm_tick.tags["queue_wait_ms"] == pytest.approx([1250.0])
+    assert (warm_tick.tags["cold"], warm_tick.tags["lanes"]) == (0, 1)
+    assert warm_tick.tags["iters"] == recs[0].solver_iters > 0
+    assert warm_tick.tags["chunks"] >= 1
+    assert warm_tick.tags["truncated"] is False
+
+    def within(outer):
+        end = outer.ts_us + outer.dur_us
+        return sorted((e for e in rec.events if e is not outer
+                       and outer.ts_us <= e.ts_us
+                       and e.ts_us + e.dur_us <= end),
+                      key=lambda e: e.ts_us)
+
+    assert [(e.name, e.depth) for e in within(cold_tick)] == \
+        [("serve/cold", 1), ("serve/cold", 1)]
+    assert [(e.name, e.depth) for e in within(warm_tick)] == [
+        ("serve/build", 1), ("serve/stack", 1), ("serve/solve", 1),
+        ("fleet/anytime", 2), ("fleet/round", 2), ("serve/apply", 1)]
+    solve = rec.spans("serve/solve")[0]
+    assert [e.name for e in within(solve)] == ["fleet/anytime",
+                                               "fleet/round"]
