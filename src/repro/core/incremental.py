@@ -1,9 +1,16 @@
 """Incremental adoption (paper §III.E): ||x - x_current||_1 <= delta_max.
 
 Implemented as an exact Euclidean projection onto the L1 ball centered at
-``x_current`` (Duchi et al. 2008), composed with the box projection by a short
-alternating (Dykstra-like) loop. Used by the controller to bound per-step
-cluster churn — the paper's "bounded perturbation" methodology.
+``x_current``, composed with the box projection by a short alternating
+(Dykstra-like) loop. Used by the controller to bound per-step cluster churn —
+the paper's "bounded perturbation" methodology.
+
+The projection is a soft threshold at Duchi et al.'s (2008) theta, found
+without a sort by Michelot's (1986) active-set fixed point: theta is the mean
+excess over the radius of the magnitudes still active, and every round drops
+the magnitudes at or below it. Each round is one compare-and-reduce pass, and
+the active set only shrinks, so the loop ends within n rounds on exactly
+Duchi's top-rho set (Condat 2016, §2).
 
 ``solve_incremental`` (the warm tick of both the myopic controller and —
 under vmap — the batched fleet engine ``solve_fleet_step``) runs the shared
@@ -29,17 +36,44 @@ from .problem import AllocationProblem
 import repro.core.objective as obj
 
 
+def _l1_threshold(a: jnp.ndarray, radius: jnp.ndarray):
+    """Soft threshold of magnitudes ``a >= 0`` onto the L1 ball of ``radius``.
+
+    Returns ``(theta, rounds)``. Michelot's fixed point: with the active set
+    ``A = {a > theta}``, set ``theta = (sum_A a - radius) / |A|`` until
+    ``|A|`` stops shrinking. It starts from the larger of two lower bounds on
+    Duchi's theta: that mean over ``{a > 0}`` (Michelot's first round) and
+    ``max(a) - radius``, which skips most rounds on a few large entries.
+    Theta only grows (in exact arithmetic by itself, under rounding by the
+    ``maximum``), so each round's set lies inside the last, ``|A|`` falls
+    every round but the last, and the loop ends within ``len(a)`` rounds on
+    Duchi's top-rho set. An empty set (a radius at or near 0) keeps the last
+    theta, which zeroes every entry. ``a`` inside the ball takes 0 rounds,
+    so under ``vmap`` it does not hold the loop open."""
+    def mean_excess(active):
+        k = jnp.sum(active, dtype=jnp.int32)
+        s = jnp.sum(jnp.where(active, a, 0.0))
+        return (s - radius) / jnp.maximum(k, 1).astype(a.dtype), k
+
+    def body(state):
+        theta, k, _, rounds = state
+        theta_new, k_new = mean_excess(a > theta)
+        return (jnp.maximum(theta, theta_new), k_new, k_new == k, rounds + 1)
+
+    theta0 = jnp.maximum(mean_excess(a > 0)[0], jnp.max(a) - radius)
+    init = (theta0, jnp.asarray(-1, jnp.int32), jnp.sum(a) <= radius,
+            jnp.asarray(0, jnp.int32))
+    theta, _, _, rounds = jax.lax.while_loop(lambda st: ~st[2], body, init)
+    return theta, rounds
+
+
 def project_l1_ball(v: jnp.ndarray, radius: jnp.ndarray) -> jnp.ndarray:
-    """Euclidean projection of v onto {z : ||z||_1 <= radius} (Duchi 2008)."""
+    """Euclidean projection of v onto {z : ||z||_1 <= radius}: soft
+    thresholding at Duchi et al.'s theta, found by the sort-free fixed point
+    of :func:`_l1_threshold`; ``v`` inside the ball comes back unchanged."""
     abs_v = jnp.abs(v)
     inside = jnp.sum(abs_v) <= radius
-    u = jnp.sort(abs_v)[::-1]
-    css = jnp.cumsum(u)
-    ks = jnp.arange(1, v.shape[0] + 1, dtype=v.dtype)
-    cond = u * ks > (css - radius)
-    rho = jnp.max(jnp.where(cond, ks, 0.0))
-    rho = jnp.maximum(rho, 1.0)
-    theta = (jnp.sum(jnp.where(ks <= rho, u, 0.0)) - radius) / rho
+    theta, _ = _l1_threshold(abs_v, radius)
     w = jnp.sign(v) * jnp.maximum(abs_v - theta, 0.0)
     return jnp.where(inside, v, w)
 
